@@ -98,6 +98,7 @@ re-queues, streamed EMA updates) for benchmarks and run logs.
 """
 from __future__ import annotations
 
+import os
 import threading
 import time
 from typing import Callable, Optional, Protocol, Tuple, runtime_checkable
@@ -377,6 +378,15 @@ def _timed_eval(fn: Callable, chunk: np.ndarray):
     return out, time.perf_counter() - t0
 
 
+def _host_worker_init() -> None:
+    """Process-pool initializer: hold the worker's JAX to the CPU before
+    it evaluates a fitness — the accelerator belongs to the manager.
+    Importing this module already imported jax, so the config is set as
+    well as the environment (inherited by the worker's own children)."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax.config.update("jax_platforms", "cpu")
+
+
 class HostPoolBackend(PureCallbackBridge):
     """Decoupled evaluation on a host executor pool via ``pure_callback``.
 
@@ -434,7 +444,8 @@ class HostPoolBackend(PureCallbackBridge):
             import multiprocessing as mp
             self._pool = cf.ProcessPoolExecutor(
                 max_workers=self.num_workers,
-                mp_context=mp.get_context("spawn"))
+                mp_context=mp.get_context("spawn"),
+                initializer=_host_worker_init)
 
     def _host_eval(self, genomes: np.ndarray,
                    perm: Optional[np.ndarray] = None,

@@ -14,6 +14,7 @@ from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import GAConfig
 from repro.core import nsga2, operators
@@ -77,7 +78,15 @@ def make_generation_step(cfg: GAConfig, broker: Broker,
             slot = jnp.arange(p)[None, :]
             keys = jnp.where(slot < pop_active, keys, 2 ** 30)
 
-        offspring = jax.vmap(one_island_variation)(step_rng, pop.genomes, keys)
+        variation = jax.vmap(one_island_variation)
+        if ctx is not None and ctx.mesh is not None:
+            # variation is island-local, and GSPMD cannot partition the
+            # Pallas kernel: each device varies its own islands
+            isp = P(ctx.dp_spec)
+            variation = jax.shard_map(variation, mesh=ctx.mesh,
+                                      in_specs=(isp, isp, isp),
+                                      out_specs=isp, check_vma=False)
+        offspring = variation(step_rng, pop.genomes, keys)
 
         # shared-pool evaluation (the broker = the paper's queue)
         flat = offspring.reshape(i * p, g)

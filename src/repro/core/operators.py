@@ -115,14 +115,11 @@ def variation(rng: jax.Array, parents: jax.Array, *, eta_cx, prob_cx,
     """
     p = parents.shape[0]
     if use_kernel and p % 2 == 0:
-        try:
-            from repro.kernels.genetic import ops as gk
-            return gk.fused_variation(
-                rng, parents, eta_cx=eta_cx, prob_cx=prob_cx,
-                eta_mut=eta_mut, prob_mut=prob_mut, indpb=indpb,
-                lower=lower, upper=upper)
-        except Exception:
-            pass
+        from repro.kernels.genetic import ops as gk
+        return gk.fused_variation(
+            rng, parents, eta_cx=eta_cx, prob_cx=prob_cx,
+            eta_mut=eta_mut, prob_mut=prob_mut, indpb=indpb,
+            lower=lower, upper=upper)
     k1, k2 = jax.random.split(rng)
     paired = parents[:p - 1] if p % 2 else parents
     p1, p2 = paired[0::2], paired[1::2]

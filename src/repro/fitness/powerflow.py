@@ -53,7 +53,7 @@ class HVDCDispatchFitness:
     def num_genes(self) -> int:
         return self.grid.n_hvdc
 
-    def _one(self, genome: jax.Array) -> jax.Array:
+    def _one(self, genome: jax.Array):
         gridj = self.gridj
         dispatch = scale_genome_to_dispatch(gridj, genome)
         p_extra = apply_hvdc(gridj, dispatch)
@@ -74,13 +74,21 @@ class HVDCDispatchFitness:
                 gridj, cases, p_extra=p_extra,
                 num_iters=self.newton_iters, ctx=self.ctx)
             base = penalized_objective(base, loadings)        # eq. (3)
-        return base[None]
+        return base[None], res.converged
 
-    def __call__(self, genomes: jax.Array) -> jax.Array:
-        out = jax.vmap(self._one)(genomes)
+    def evaluate(self, genomes: jax.Array):
+        """(N, H) genomes -> ((N, 1) objectives, (N,) base-case Newton
+        converged). Genomes are solved one at a time (``lax.map``): at
+        German-grid size the TPU compiler refuses a batched LU of the
+        Jacobian (its panel overflows the scoped VMEM), while one solve
+        compiles, and device memory stays that of one solve."""
+        out, converged = jax.lax.map(self._one, genomes)
         if self.ctx is not None and self.ctx.mesh is not None and self.ctx.dp:
             out = self.ctx.cs(out, self.ctx.dp_spec, None)
-        return out
+        return out, converged
+
+    def __call__(self, genomes: jax.Array) -> jax.Array:
+        return self.evaluate(genomes)[0]
 
     def cost_model(self):
         """Predicted per-genome evaluation cost for the broker: Newton

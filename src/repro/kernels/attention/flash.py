@@ -23,10 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x names this TPUCompilerParams; >=0.5 renamed it
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 _MIN = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
@@ -132,7 +128,7 @@ def flash_attention_fwd(q, k, v, *, scale, causal=True, window=0,
             pltpu.VMEM((g * bq, 128), jnp.float32),
             pltpu.VMEM((g * bq, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qg, kg, vg)

@@ -82,10 +82,11 @@ def _kernel(scalars, x1, x2, u_cx, m_pair, m_gene, u_mut1, u_mut2,
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def fused_variation_pallas(x1, x2, rnd, scalars, lower, upper, *,
-                           block_rows: int = 256, interpret: bool = True):
+                           interpret: bool, block_rows: int = 256):
     """x1/x2: (P2, G); rnd: dict from ref.draw_uniforms (split per child);
     scalars: (5,) [eta_cx, prob_cx, eta_mut, prob_mut, indpb].
-    Returns (o1, o2) each (P2, G)."""
+    ``interpret``: True runs the kernel body in Python semantics (CPU);
+    False compiles it for the TPU. Returns (o1, o2) each (P2, G)."""
     p2, g = x1.shape
     gp = max(128, -(-g // 128) * 128)                # lane-pad gene axis
     bp = min(block_rows, p2)

@@ -17,10 +17,7 @@ from repro.kernels.genetic.ref import draw_uniforms, fused_variation_ref
 
 
 def _is_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def fused_variation(rng: jax.Array, parents: jax.Array, *, eta_cx, prob_cx,

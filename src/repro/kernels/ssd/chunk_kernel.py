@@ -27,10 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x names this TPUCompilerParams; >=0.5 renamed it
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _kernel(a_vec, x_ref, dt_ref, b_ref, c_ref, y_ref, st_ref, dec_ref, *,
             chunk: int):
@@ -106,7 +102,7 @@ def ssd_intra_chunk(x, dt, a, b_mat, c_mat, *, chunk: int,
             jax.ShapeDtypeStruct((bsz * nc, h, p, n), jnp.float32),
             jax.ShapeDtypeStruct((bsz * nc, h, 1, chunk), jnp.float32),
         ),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(a.astype(jnp.float32), xr, dtr, br, cr)

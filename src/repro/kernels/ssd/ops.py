@@ -13,10 +13,7 @@ from repro.kernels.ssd.chunk_kernel import ssd_intra_chunk
 
 
 def _is_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def ssd_chunked(x, dt, a, b_mat, c_mat, chunk: int,

@@ -185,6 +185,7 @@ from repro.configs.base import GAConfig
 from repro.core.engine import GAEngine
 from repro.core.scaling import plan_scaling
 from repro.checkpoint import Checkpointer
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def build(fitness_name: str, args):
@@ -371,6 +372,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
     # odd --pop is fine: operators.variation carries the unpaired last
     # parent through mutation-only
+    enable_compile_cache()
 
     cfg, fitness_fn, cost_fn = build(args.fitness, args)
     if args.cost_ema:

@@ -9,38 +9,34 @@ Single pod: v5e 16x16 (256 chips), axes (data, model).
 Multi-pod:  2 pods = 512 chips, axes (pod, data, model) — `pod` is pure
 data parallelism across the inter-pod links (optionally with compressed
 gradient reduction, train/compress.py).
+
+Every mesh built here has Auto axes: sharding comes from the GSPMD
+partitioner and ``with_sharding_constraint`` (``jax.make_mesh`` alone
+gives Explicit axes, which reject those constraints).
 """
 from __future__ import annotations
 
+import math
+
 import jax
-
-try:                     # jax >= 0.5: explicit-sharding axis types
-    from jax.sharding import AxisType
-except ImportError:      # jax 0.4.x: every mesh axis is implicitly Auto
-    AxisType = None
+from jax.sharding import AxisType
 
 
-def _axis_kwargs(num_axes: int) -> dict:
-    """`axis_types` kwarg when this jax supports it (all Auto — the GSPMD
-    partitioner behavior 0.4.x gives unconditionally), else nothing."""
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * num_axes}
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` over ``devices`` (default: the first
+    prod(shape) local devices) with every axis Auto."""
+    if devices is None:
+        devices = jax.devices()[:math.prod(shape)]
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = 1
-    for s in shape:
-        n *= s
-    devices = jax.devices()[:n]
-    return jax.make_mesh(shape, axes, devices=devices, **_axis_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Mesh over whatever devices exist locally (tests / examples)."""
-    n = data * model
-    devices = jax.devices()[:n]
-    return jax.make_mesh((data, model), ("data", "model"), devices=devices,
-                         **_axis_kwargs(2))
+    return make_mesh((data, model), ("data", "model"))

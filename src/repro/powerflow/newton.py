@@ -53,7 +53,17 @@ def newton_powerflow(gridj: dict, *, p_extra: jax.Array | None = None,
     injections (HVDC terms). line_mask: optional (L,) {0,1} line in-service
     mask (contingencies) — the Ybus is rebuilt from branch data so outages
     are expressible inside jit.
+
+    Every matmul and the LU solve run at HIGHEST precision: the TPU's
+    default f32 matmul rounds its inputs to bf16, which leaves the
+    mismatch far above ``tol`` (even exact f32 floors at ~2e-4 p.u. on the
+    German grid).
     """
+    with jax.default_matmul_precision("highest"):
+        return _newton_powerflow(gridj, p_extra, num_iters, tol, line_mask)
+
+
+def _newton_powerflow(gridj, p_extra, num_iters, tol, line_mask) -> PFResult:
     bt = gridj["bus_type"]
     n = bt.shape[0]
     is_slack = bt == 2

@@ -174,6 +174,18 @@ _SRC_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 
+def worker_env() -> dict:
+    """Environment for a local host-side worker subprocess: the `repro`
+    package on PYTHONPATH, and JAX held to the CPU — the accelerator
+    belongs to the manager process, and a worker that unpickles a JAX
+    fitness must not try to take it."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = _SRC_ROOT + (
+        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    env["JAX_PLATFORMS"] = "cpu"
+    return env
+
+
 # ---------------------------------------------------------------------------
 # Chunk files (spool protocol)
 # ---------------------------------------------------------------------------
@@ -271,13 +283,10 @@ def _spawn_local_worker(path: str, mode: str, python: str,
     if any(s in os.path.basename(path) for s in hang_substrings):
         return None
     if mode == "subprocess":
-        env = dict(os.environ)
-        env["PYTHONPATH"] = _SRC_ROOT + (
-            os.pathsep + env["PYTHONPATH"]
-            if env.get("PYTHONPATH") else "")
         return subprocess.Popen(
             [python, "-m", "repro.runtime.batchq", "--worker", path],
-            env=env, stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
+            env=worker_env(), stdout=subprocess.DEVNULL,
+            stderr=subprocess.DEVNULL)
     task = threading.Thread(target=run_worker, args=(path,), daemon=True)
     task.start()
     return task

@@ -272,7 +272,7 @@ import numpy as np
 from repro.core.hostbridge import (PureCallbackBridge, collect_chunk_results,
                                    plan_cost_chunks, scatter_chunk_results)
 from repro.runtime import metrics as _metrics
-from repro.runtime.batchq import _PAYLOAD, _SRC_ROOT, resolve_fn
+from repro.runtime.batchq import _PAYLOAD, resolve_fn, worker_env
 from repro.runtime.fsatomic import (TMP_SUFFIX, atomic_savez,
                                     atomic_write_bytes, atomic_write_json,
                                     atomic_write_text)
@@ -966,10 +966,6 @@ class LocalWorkerPool:
             t.start()
             self._members.append(t)
         else:
-            env = dict(os.environ)
-            env["PYTHONPATH"] = _SRC_ROOT + (
-                os.pathsep + env["PYTHONPATH"]
-                if env.get("PYTHONPATH") else "")
             cmd = [self.python, "-m", "repro.runtime.mq", "--worker",
                    "--mq-dir", self.mq_dir,
                    "--lease-s", str(self.lease_s),
@@ -978,7 +974,7 @@ class LocalWorkerPool:
                 cmd += ["--hang-substrings",
                         ",".join(self.hang_substrings)]
             self._members.append(subprocess.Popen(
-                cmd, env=env, stdout=subprocess.DEVNULL,
+                cmd, env=worker_env(), stdout=subprocess.DEVNULL,
                 stderr=subprocess.DEVNULL))
 
     def start(self):

@@ -128,13 +128,10 @@ import numpy as np
 
 from repro.runtime import metrics as _metrics
 from repro.runtime import mq
+from repro.runtime.batchq import worker_env
 from repro.runtime.fsatomic import (atomic_write_bytes, atomic_write_text)
 from repro.runtime.mq import (LEASE_SUFFIX, POISON_SUFFIX, STOP_NAME,
                               QueueBackend, parse_task_name)
-
-#: repo src/ root, for subprocess-mode worker PYTHONPATH
-_SRC_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
 
 # ---------------------------------------------------------------------------
 # Frame codec
@@ -1038,10 +1035,6 @@ class NetWorkerPool:
             self._members.append(t)
         else:
             import subprocess
-            env = dict(os.environ)
-            env["PYTHONPATH"] = _SRC_ROOT + (
-                os.pathsep + env["PYTHONPATH"]
-                if env.get("PYTHONPATH") else "")
             cmd = [self.python, "-m", "repro.runtime.netbroker",
                    "--worker",
                    "--broker-addr", f"{self.addr[0]}:{self.addr[1]}",
@@ -1051,7 +1044,7 @@ class NetWorkerPool:
                 cmd += ["--hang-substrings",
                         ",".join(self.hang_substrings)]
             self._members.append(subprocess.Popen(
-                cmd, env=env, stdout=subprocess.DEVNULL,
+                cmd, env=worker_env(), stdout=subprocess.DEVNULL,
                 stderr=subprocess.DEVNULL))
 
     def start(self) -> "NetWorkerPool":

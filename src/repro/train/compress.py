@@ -51,37 +51,3 @@ def compressed_psum_tree(grads: Any, axis_name: str, rng: jax.Array) -> Any:
 
     out = [reduce_one(x, r) for x, r in zip(leaves, rngs)]
     return jax.tree_util.tree_unflatten(treedef, out)
-
-
-def compressed_allgather_mean(stacked: Any, rng: jax.Array, *,
-                              mesh=None, axis_name: str = "pod") -> Any:
-    """GSPMD formulation of the compressed mean (no shard_map).
-
-    Leaves carry a leading per-pod axis (sharded over `axis_name` when a
-    mesh is given). Quantize each pod's slice to int8, then express the
-    "all_gather(int8) + local sum" as a replication constraint on the int8
-    operand — GSPMD lowers the reshard to an all-gather whose wire format
-    really is 8-bit — followed by a local dequantize-sum. Used on jax 0.4.x
-    where a partial-manual shard_map body trips the XLA partitioner
-    (IsManualSubgroup check); numerically identical to
-    :func:`compressed_psum_tree` up to per-pod rng streams.
-    """
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    leaves, treedef = jax.tree_util.tree_flatten(stacked)
-    rngs = jax.random.split(rng, len(leaves))
-
-    def reduce_one(x, r):
-        n = x.shape[0]
-        q, scale = jax.vmap(quantize)(x, jax.random.split(r, n))
-        if mesh is not None and axis_name in mesh.axis_names:
-            rep = NamedSharding(mesh, P(*(None,) * q.ndim))
-            q = jax.lax.with_sharding_constraint(q, rep)     # int8 gather
-            scale = jax.lax.with_sharding_constraint(
-                scale, NamedSharding(mesh, P(None)))
-        summed = jnp.sum(q.astype(jnp.float32)
-                         * scale.reshape((n,) + (1,) * (q.ndim - 1)), axis=0)
-        return (summed / n).astype(x.dtype)
-
-    out = [reduce_one(x, r) for x, r in zip(leaves, rngs)]
-    return jax.tree_util.tree_unflatten(treedef, out)

@@ -6,8 +6,8 @@ plus the model's internal constraints (GSPMD). Microbatch accumulation runs
 as a ``lax.scan`` so the activation peak is one microbatch.
 
 Optional ``compress_pod_reduce``: the cross-pod gradient reduction is
-executed as an int8 all-gather + local sum inside a partial-manual
-``shard_map`` over the ``pod`` axis (see train/compress.py). In that mode
+executed as an int8 all-gather + local sum inside a ``shard_map``
+(see train/compress.py). In that mode
 the per-pod loss is averaged over the pod-local batch shard, and pods are
 synchronized exclusively through the compressed reduce.
 """
@@ -141,65 +141,35 @@ def _pod_compressed_grads(model, microbatches, unroll, params, batch, rng):
     (FSDP within a pod only) — the natural layout when inter-pod links are
     slow enough to warrant compression.
 
-    Two lowering strategies, same numerics:
-      * jax >= 0.6: partial-manual ``jax.shard_map`` over 'pod';
-        'data'/'model' stay under GSPMD inside the body, the reduce is an
-        explicit int8 ``all_gather`` (compress.compressed_psum_tree).
-      * jax 0.4.x: a partial-manual body trips the XLA partitioner
-        (``IsManualSubgroup`` check), so the pod axis is expressed as a
-        vmapped leading batch dimension sharded over 'pod', and the int8
-        gather as a GSPMD replication constraint
-        (compress.compressed_allgather_mean).
+    Lowered as a ``jax.shard_map`` that is manual over every mesh axis:
+    each device computes grads on its (pod, dp) batch shard, the dp mean
+    is an exact f32 ``pmean``, and the cross-pod reduce is an explicit
+    int8 ``all_gather`` (compress.compressed_psum_tree). Params enter
+    replicated; the model axis computes redundantly. (A shard_map manual
+    over 'pod' alone, with 'data'/'model' left to GSPMD inside, aborts in
+    XLA's SPMD partitioner.)
     """
     import dataclasses
 
     ctx = model.ctx
-    mesh = ctx.mesh
-    drop_pod = lambda axes: tuple(a for a in axes if a != "pod")
-    inner_ctx = dataclasses.replace(ctx, dp=drop_pod(ctx.dp),
-                                    fsdp=drop_pod(ctx.fsdp))
-
-    if hasattr(jax, "shard_map"):
-        inner_model = model.with_ctx(inner_ctx)
-        compute_grads = make_compute_grads(inner_model, microbatches, unroll)
-
-        def per_pod(params, batch, rng):
-            grads, metrics = compute_grads(params, batch)
-            grads = compressed_psum_tree(grads, "pod", rng)
-            metrics = jax.tree_util.tree_map(
-                lambda x: jax.lax.pmean(x, "pod"), metrics)
-            return grads, metrics
-
-        pspecs = jax.tree_util.tree_map(lambda _: P(), params)
-        bspecs = jax.tree_util.tree_map(lambda _: P("pod"), batch)
-        f = jax.shard_map(per_pod, mesh=mesh,
-                          in_specs=(pspecs, bspecs, P()),
-                          out_specs=(pspecs, P()),
-                          axis_names={"pod"}, check_vma=False)
-        return f(params, batch, rng)
-
-    # jax 0.4.x GSPMD path: pods = vmapped leading axis. The inner
-    # constraints are dropped (mesh=None ctx) — under vmap they would
-    # apply to per-pod slices; GSPMD auto-partitions the body instead.
-    from jax.sharding import NamedSharding
-    from repro.train.compress import compressed_allgather_mean
-
-    n_pods = mesh.shape["pod"]
+    inner_dp = tuple(a for a in ctx.dp if a != "pod")
     inner_model = model.with_ctx(dataclasses.replace(ctx, mesh=None))
     compute_grads = make_compute_grads(inner_model, microbatches, unroll)
 
-    def split_pods(x):
-        x = x.reshape((n_pods, x.shape[0] // n_pods) + x.shape[1:])
-        return jax.lax.with_sharding_constraint(
-            x, NamedSharding(mesh, P(*("pod",) + (None,) * (x.ndim - 1))))
+    def per_device(params, batch, rng):
+        grads, metrics = compute_grads(params, batch)
+        if inner_dp:
+            grads = jax.lax.pmean(grads, inner_dp)
+        grads = compressed_psum_tree(grads, "pod", rng)
+        metrics = jax.lax.pmean(metrics, ("pod",) + inner_dp)
+        return grads, metrics
 
-    batch_p = jax.tree_util.tree_map(split_pods, batch)
-    grads_p, metrics_p = jax.vmap(
-        compute_grads, in_axes=(None, 0))(params, batch_p)
-    grads = compressed_allgather_mean(grads_p, rng, mesh=mesh)
-    metrics = jax.tree_util.tree_map(lambda m: jnp.mean(m, axis=0),
-                                     metrics_p)
-    return grads, metrics
+    pspecs = jax.tree_util.tree_map(lambda _: P(), params)
+    bspecs = jax.tree_util.tree_map(lambda _: P(("pod",) + inner_dp), batch)
+    f = jax.shard_map(per_device, mesh=ctx.mesh,
+                      in_specs=(pspecs, bspecs, P()),
+                      out_specs=(pspecs, P()), check_vma=False)
+    return f(params, batch, rng)
 
 
 def init_train_state(model: Model, rng: jax.Array,

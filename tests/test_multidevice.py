@@ -69,10 +69,9 @@ from repro.models.model import Model
 from repro.models.sharding import ShardingCtx
 from repro.train.train_step import make_train_step, init_train_state
 from repro.train.optimizer import OptimizerConfig
-from repro.launch.mesh import make_local_mesh
-import jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 cfg = get_config("tinyllama-1.1b").reduced()
 # compressed mode: pure DP across pods (params replicated over pod)
 ctx = ShardingCtx(mesh=mesh, dp=("pod", "data"), tp="model",

@@ -118,6 +118,22 @@ def test_variation_odd_pop_under_jit_and_kernel_flag():
         assert bool(jnp.all(jnp.isfinite(off)))
 
 
+def test_variation_kernel_failure_raises(monkeypatch):
+    """A failing fused kernel surfaces; variation never swaps in the
+    unfused operators behind the caller's back."""
+    from repro.kernels.genetic import ops as gk
+
+    def broken(*args, **kwargs):
+        raise RuntimeError("fused kernel failed")
+
+    monkeypatch.setattr(gk, "fused_variation", broken)
+    parents = jax.random.uniform(KEY, (16, 4), minval=-1, maxval=1)
+    with pytest.raises(RuntimeError, match="fused kernel failed"):
+        operators.variation(KEY, parents, eta_cx=15.0, prob_cx=0.9,
+                            eta_mut=20.0, prob_mut=0.7, indpb=0.3,
+                            lower=-1.0, upper=1.0, use_kernel=True)
+
+
 def test_traced_hyperparams():
     """Operators must accept traced eta/prob (meta-GA requirement)."""
     parents = jax.random.uniform(KEY, (8, 3))

@@ -139,3 +139,18 @@ class TestFitnessBackend:
         c0 = cost(jnp.zeros((1, 4)))
         c1 = cost(jnp.ones((1, 4)))
         assert float(c1[0]) > float(c0[0])
+
+    def test_hvdc_one_genome_at_a_time_matches_vmap(self, small_grid):
+        """The per-genome ``lax.map`` form gives the batched-vmap values,
+        and reports base-case convergence per genome."""
+        from repro.fitness.powerflow import HVDCDispatchFitness
+        fit = HVDCDispatchFitness(small_grid, newton_iters=10)
+        genomes = jax.random.uniform(jax.random.PRNGKey(5), (6, 4),
+                                     minval=-1.0, maxval=1.0)
+        out, converged = jax.jit(fit.evaluate)(genomes)
+        batched = jax.jit(jax.vmap(lambda g: fit._one(g)[0]))(genomes)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(batched),
+                                   rtol=1e-5)
+        assert converged.shape == (6,) and bool(jnp.all(converged))
+        np.testing.assert_array_equal(np.asarray(jax.jit(fit)(genomes)),
+                                      np.asarray(out))
