@@ -129,4 +129,3 @@ def test_german_grid_hvdc_evaluation_compiles_for_v5e(one_chip):
                              sharding=one_chip)).compile()
     # one genome's solve at a time: temporaries stay near one solve's
     assert compiled.memory_analysis().temp_size_in_bytes < 1e9
-
