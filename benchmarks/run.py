@@ -5,7 +5,6 @@
   fig5_*           — horizontal vs vertical HVDC scaling (Fig. 5)
   fig6_metaga      — meta-GA hyperparameter evolution (Fig. 6)
   broker/operator  — framework overhead microbench (Tab. 1 / §3 claims)
-  roofline         — three-term roofline per dry-run cell (EXPERIMENTS.md)
 
 Pass --quick for the fast subset (CI); --only NAME to run one section.
 --json PATH dumps every section's rows machine-readably (the default
@@ -85,11 +84,6 @@ def main(argv=None) -> None:
             epochs=1 if args.quick else 2,
             pop=6 if args.quick else 8,
             inner_generations=4 if args.quick else 6)
-
-    if want("roofline"):
-        from benchmarks import roofline
-        print("# --- roofline terms from the dry-run ---")
-        sections["roofline"] = roofline.run()
 
     if args.json:
         write_bench_json(args.json, sections)

@@ -369,6 +369,15 @@ class InlineBackend:
     def __call__(self, genomes: jax.Array) -> jax.Array:
         return self.fitness_fn(genomes)
 
+    def evaluate_with_stats(self, genomes: jax.Array) -> Tuple[jax.Array, dict]:
+        """(fitness, the fitness's own batch sums): a fitness that counts
+        its work (``evaluate_with_stats``, e.g. the HVDC fitness's Newton
+        iterations) hands the counts on; any other gives none."""
+        fn = getattr(self.fitness_fn, "evaluate_with_stats", None)
+        if fn is None:
+            return self.fitness_fn(genomes), {}
+        return fn(genomes)
+
 
 def _timed_eval(fn: Callable, chunk: np.ndarray):
     """Evaluate one chunk, returning (fitness, wall_seconds). Module-level
@@ -578,25 +587,40 @@ class Broker:
         return {"skew": one, "naive_skew": one, "balanced": jnp.zeros(()),
                 "padded": jnp.zeros((), jnp.int32)}
 
+    def _backend_eval(self, genomes: jax.Array) -> Tuple[jax.Array, dict]:
+        with jax.named_scope("chambga.fitness"):
+            fn = getattr(self.backend, "evaluate_with_stats", None)
+            if fn is None:
+                return self.backend(genomes), {}
+            return fn(genomes)
+
     def evaluate(self, genomes: jax.Array) -> Tuple[jax.Array, dict]:
         """genomes: (N, G) -> (fitness (N, O), dispatch stats).
 
         Total: cost-balanced dispatch applies for EVERY N/num_workers
         combination when a cost model is given (no silent identity
         fallback); padding absorbs N % W != 0.
+
+        ``stats["fitness"]`` holds the inline fitness's own batch sums
+        (empty where the fitness or backend keeps none); padded lanes are
+        evaluated, so they count. Profiler scopes: the backend call is
+        ``chambga.fitness``; the cost model, permutation, gather and
+        inverse are ``chambga.dispatch``.
         """
         n = genomes.shape[0]
         w = self.num_workers
         if self.cost_fn is None or w <= 1:
-            fit = self.backend(genomes)
-            return fit, self._identity_stats()
-        cost = self.cost_fn(genomes)
-        perm = balanced_permutation(cost, w)                # (Np,)
-        n_pad = perm.shape[0]
-        real = perm < n                                     # pad mask
-        shuffled = padded_take(genomes, perm, n)            # the "all-to-all"
-        # predicted per-slot cost in shuffled order (pads carry zero)
-        lane_cost = jnp.where(real, padded_take(cost, perm, n), 0.0)
+            fit, fstats = self._backend_eval(genomes)
+            return fit, dict(self._identity_stats(), fitness=fstats)
+        with jax.named_scope("chambga.dispatch"):
+            cost = self.cost_fn(genomes)
+            perm = balanced_permutation(cost, w)            # (Np,)
+            n_pad = perm.shape[0]
+            real = perm < n                                 # pad mask
+            shuffled = padded_take(genomes, perm, n)        # the "all-to-all"
+            # predicted per-slot cost in shuffled order (pads carry zero)
+            lane_cost = jnp.where(real, padded_take(cost, perm, n), 0.0)
+        fstats = {}
         if hasattr(self.backend, "eval_with_perm"):
             # decoupled backend: `perm` keys measured per-chunk wall times
             # back into the EMA cost model, and the cost operand drives
@@ -607,12 +631,14 @@ class Broker:
             # results are dropped by the masked inverse anyway), not
             # mistake them for free work
             pad_marked = jnp.where(real, lane_cost, -jnp.inf)
-            fit_shuf = self.backend.eval_with_perm(shuffled, perm,
-                                                   pad_marked)
+            with jax.named_scope("chambga.fitness"):
+                fit_shuf = self.backend.eval_with_perm(shuffled, perm,
+                                                       pad_marked)
         else:
-            fit_shuf = self.backend(shuffled)
-        inv = inverse_permutation(perm, n)
-        fit = jnp.take(fit_shuf, inv, axis=0)
+            fit_shuf, fstats = self._backend_eval(shuffled)
+        with jax.named_scope("chambga.dispatch"):
+            inv = inverse_permutation(perm, n)
+            fit = jnp.take(fit_shuf, inv, axis=0)
         # stats: per-worker predicted load skew (max/mean), before/after;
         # padded lanes contribute zero load
         loads = jnp.sum(lane_cost.reshape(w, n_pad // w), axis=1)
@@ -625,5 +651,6 @@ class Broker:
             "naive_skew": jnp.max(naive) / jnp.maximum(jnp.mean(naive), 1e-9),
             "balanced": jnp.ones(()),
             "padded": jnp.full((), n_pad - n, jnp.int32),
+            "fitness": fstats,
         }
         return fit, stats

@@ -13,6 +13,16 @@ immediately, and the blocking ``device_get`` of epoch e is deferred until
 epoch e+``pipeline_depth`` has been dispatched — the manager-side
 counterpart of the paper's non-blocking queue submission. ``sync_every``
 additionally batches how often the pending queue is drained.
+
+Observability: the host steps are profiler spans, each with ``epoch=<n>``
+(``chambga.init`` the initial evaluation, ``chambga.dispatch`` the epoch
+step's call, any retrace or recompile included, ``chambga.drain`` the
+blocking metric read, ``chambga.checkpoint`` a save). Each drained epoch
+is published through the ``repro.runtime.metrics`` seam as
+``chambga_epochs_total`` and ``chambga_evaluations_total``, and the
+fitness's own batch sums (the HVDC fitness's Newton iterations, solves
+and unconverged solves) as ``chambga_<name>_total``; the history record
+carries the same sums.
 """
 from __future__ import annotations
 
@@ -31,6 +41,7 @@ from repro.core.island import (evaluate_population, make_epoch_step,
 from repro.core.population import (Population, best_of, evals_dtype,
                                    init_population)
 from repro.models.sharding import ShardingCtx
+from repro.runtime import metrics as _metrics
 
 
 def _start_host_copy(tree) -> None:
@@ -89,7 +100,8 @@ class GAEngine:
         pop = init_population(self.cfg, rng)
         pop = constrain_pop(pop, self.ctx)
         self.evals_host = self.cfg.global_pop
-        return self._init_eval(pop)
+        with jax.profiler.TraceAnnotation("chambga.init", epoch=0):
+            return self._init_eval(pop)
 
     def restore(self, step: Optional[int] = None) -> Optional[Population]:
         if self.checkpointer is None:
@@ -158,17 +170,28 @@ class GAEngine:
     # ------------------------------------------------------------------
     def _drain(self, pending: list, history: list, keep: int = 0) -> None:
         """Blocking-read all but the newest `keep` pending epoch metrics
-        into `history` (oldest first)."""
+        into `history` (oldest first), and publish each epoch's counts."""
+        m = _metrics.get_registry()
         while len(pending) > keep:
             ee, mm = pending.pop(0)
-            mm = jax.device_get(mm)
+            with jax.profiler.TraceAnnotation("chambga.drain", epoch=ee):
+                mm = jax.device_get(mm)
+            sums = {k: int(np.sum(v))
+                    for k, v in mm.get("fitness", {}).items()}
             rec = {"epoch": ee,
                    "best_per_island": np.asarray(mm["best"])[-1],
                    "best": float(np.min(mm["best"])),
                    "trace": np.asarray(mm["best"]),
                    "skew": float(np.mean(mm["skew"])),
-                   "balanced": float(np.mean(mm.get("balanced", 0.0)))}
+                   "balanced": float(np.mean(mm.get("balanced", 0.0))),
+                   **sums}
             history.append(rec)
+            if m.enabled:
+                m.inc("chambga_epochs_total")
+                m.inc("chambga_evaluations_total", float(
+                    self.cfg.generations_per_epoch * self.cfg.global_pop))
+                for k, v in sums.items():
+                    m.inc(f"chambga_{k}_total", float(v))
             if self.log_fn:
                 self.log_fn(rec)
 
@@ -201,7 +224,8 @@ class GAEngine:
                            * pop.genomes.shape[0] * pop.genomes.shape[1])
 
         for e in range(start_epoch, start_epoch + epochs):
-            pop, metrics = self._epoch_step(pop)
+            with jax.profiler.TraceAnnotation("chambga.dispatch", epoch=e):
+                pop, metrics = self._epoch_step(pop)
             self.evals_host += evals_per_epoch         # exact, unbounded
             _start_host_copy(metrics)                  # non-blocking D2H
             pending.append((e, metrics))
@@ -218,15 +242,17 @@ class GAEngine:
                     break
             if self.checkpointer and self.checkpoint_every and \
                     (e + 1) % self.checkpoint_every == 0:
-                self.checkpointer.save(self._checkpoint_state(pop),
-                                       step=e + 1)
+                self._save(pop, e + 1)
             if wallclock_s is not None and time.monotonic() - t0 > wallclock_s:
                 break
         self._drain(pending, history, keep=0)
         if self.checkpointer and self.checkpoint_every:
-            self.checkpointer.save(self._checkpoint_state(pop),
-                                   step=int(jax.device_get(pop.epoch)))
+            self._save(pop, int(jax.device_get(pop.epoch)))
         return pop, history
+
+    def _save(self, pop: Population, epoch: int) -> None:
+        with jax.profiler.TraceAnnotation("chambga.checkpoint", epoch=epoch):
+            self.checkpointer.save(self._checkpoint_state(pop), step=epoch)
 
     def best(self, pop: Population):
         g, f = jax.device_get(best_of(pop))

@@ -6,6 +6,12 @@ collectives** (the paper's "removal of synchronization barriers") — then a
 single ring migration. The island axis shards over the mesh `data` axis, so
 migration lowers to a CollectivePermute and the broker's balanced dispatch
 to an all-to-all; everything else is island-local.
+
+Each stage carries a profiler scope (``jax.named_scope``), so a device
+trace names its ops: ``chambga.selection`` (NSGA-II keys and the
+tournament), ``chambga.variation``, ``chambga.fitness`` and
+``chambga.dispatch`` (the broker), ``chambga.survivor`` and
+``chambga.migration``.
 """
 from __future__ import annotations
 
@@ -57,15 +63,16 @@ def make_generation_step(cfg: GAConfig, broker: Broker,
 
     def one_island_variation(rng, genomes, key):
         k_sel, k_var = jax.random.split(rng)
-        parents_idx = operators.tournament_select(
-            k_sel, key.astype(jnp.float32), cfg.pop_per_island,
-            active=pop_active, tsize=cfg.tournament_size)
-        parents = genomes[parents_idx]
-        off = operators.variation(
-            k_var, parents, eta_cx=eta_cx, prob_cx=prob_cx,
-            eta_mut=eta_mut, prob_mut=prob_mut, indpb=indpb,
-            lower=lo, upper=hi, use_kernel=cfg.fused_operators)
-        return off
+        with jax.named_scope("chambga.selection"):
+            parents_idx = operators.tournament_select(
+                k_sel, key.astype(jnp.float32), cfg.pop_per_island,
+                active=pop_active, tsize=cfg.tournament_size)
+            parents = genomes[parents_idx]
+        with jax.named_scope("chambga.variation"):
+            return operators.variation(
+                k_var, parents, eta_cx=eta_cx, prob_cx=prob_cx,
+                eta_mut=eta_mut, prob_mut=prob_mut, indpb=indpb,
+                lower=lo, upper=hi, use_kernel=cfg.fused_operators)
 
     def generation(pop: Population, _=None) -> Tuple[Population, dict]:
         i, p, g = pop.genomes.shape
@@ -73,10 +80,11 @@ def make_generation_step(cfg: GAConfig, broker: Broker,
         step_rng, next_rng = rngs[:, 0], rngs[:, 1]
 
         # island-local selection keys (rank, crowding)
-        _, _, keys = jax.vmap(nsga2.nsga2_keys)(pop.fitness)
-        if pop_active is not None:
-            slot = jnp.arange(p)[None, :]
-            keys = jnp.where(slot < pop_active, keys, 2 ** 30)
+        with jax.named_scope("chambga.selection"):
+            _, _, keys = jax.vmap(nsga2.nsga2_keys)(pop.fitness)
+            if pop_active is not None:
+                slot = jnp.arange(p)[None, :]
+                keys = jnp.where(slot < pop_active, keys, 2 ** 30)
 
         variation = jax.vmap(one_island_variation)
         if ctx is not None and ctx.mesh is not None:
@@ -97,10 +105,11 @@ def make_generation_step(cfg: GAConfig, broker: Broker,
             off_fit = jnp.where(slot < pop_active, off_fit, jnp.inf)
 
         # (mu+lambda) island-local survivor selection
-        comb_g = jnp.concatenate([pop.genomes, offspring], axis=1)
-        comb_f = jnp.concatenate([pop.fitness, off_fit], axis=1)
-        new_g, new_f = jax.vmap(lambda gg, ff: nsga2.survivor_select(
-            gg, ff, p))(comb_g, comb_f)
+        with jax.named_scope("chambga.survivor"):
+            comb_g = jnp.concatenate([pop.genomes, offspring], axis=1)
+            comb_f = jnp.concatenate([pop.fitness, off_fit], axis=1)
+            new_g, new_f = jax.vmap(lambda gg, ff: nsga2.survivor_select(
+                gg, ff, p))(comb_g, comb_f)
 
         newpop = Population(
             genomes=new_g, fitness=new_f, rng=next_rng,
@@ -109,7 +118,8 @@ def make_generation_step(cfg: GAConfig, broker: Broker,
         newpop = constrain_pop(newpop, ctx)
         metrics = {"best": jnp.min(new_f[..., 0], axis=1),   # per island
                    "skew": stats["skew"],
-                   "balanced": stats["balanced"]}
+                   "balanced": stats["balanced"],
+                   "fitness": stats["fitness"]}
         return newpop, metrics
 
     return generation
@@ -184,7 +194,8 @@ def make_epoch_step(cfg: GAConfig, broker: Broker,
     def epoch_step(pop: Population) -> Tuple[Population, dict]:
         pop, metrics = jax.lax.scan(
             generation, pop, None, length=cfg.generations_per_epoch)
-        pop = migrate_ring(cfg, pop, ctx)
+        with jax.named_scope("chambga.migration"):
+            pop = migrate_ring(cfg, pop, ctx)
         # metrics: (M, I) best trace per generation
         return pop, metrics
 
@@ -195,6 +206,7 @@ def evaluate_population(cfg: GAConfig, broker: Broker,
                         pop: Population) -> Population:
     """Initial fitness evaluation of a fresh population."""
     i, p, g = pop.genomes.shape
-    fit, _ = broker.evaluate(pop.genomes.reshape(i * p, g))
+    with jax.named_scope("chambga.fitness"):
+        fit, _ = broker.evaluate(pop.genomes.reshape(i * p, g))
     return pop._replace(fitness=fit.reshape(i, p, -1),
                         evals=pop.evals + i * p)

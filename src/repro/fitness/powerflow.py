@@ -74,18 +74,36 @@ class HVDCDispatchFitness:
                 gridj, cases, p_extra=p_extra,
                 num_iters=self.newton_iters, ctx=self.ctx)
             base = penalized_objective(base, loadings)        # eq. (3)
-        return base[None], res.converged
+        return base[None], res.converged, res.iters
+
+    def _solve(self, genomes: jax.Array):
+        # one genome at a time (``lax.map``): at German-grid size the TPU
+        # compiler refuses a batched LU of the Jacobian (its panel
+        # overflows the scoped VMEM), while one solve compiles, and
+        # device memory stays that of one solve
+        out, converged, iters = jax.lax.map(self._one, genomes)
+        if self.ctx is not None and self.ctx.mesh is not None and self.ctx.dp:
+            out = self.ctx.cs(out, self.ctx.dp_spec, None)
+        return out, converged, iters
 
     def evaluate(self, genomes: jax.Array):
         """(N, H) genomes -> ((N, 1) objectives, (N,) base-case Newton
-        converged). Genomes are solved one at a time (``lax.map``): at
-        German-grid size the TPU compiler refuses a batched LU of the
-        Jacobian (its panel overflows the scoped VMEM), while one solve
-        compiles, and device memory stays that of one solve."""
-        out, converged = jax.lax.map(self._one, genomes)
-        if self.ctx is not None and self.ctx.mesh is not None and self.ctx.dp:
-            out = self.ctx.cs(out, self.ctx.dp_spec, None)
+        converged)."""
+        out, converged, _ = self._solve(genomes)
         return out, converged
+
+    def evaluate_with_stats(self, genomes: jax.Array):
+        """(N, H) genomes -> ((N, 1) objectives, batch sums of the
+        base-case Newton solves: ``newton_iterations`` that updated the
+        voltages (``PFResult.iters``), ``newton_solves`` and
+        ``newton_unconverged``). The broker forwards the sums to the
+        engine, which publishes them as ``chambga_<name>_total``."""
+        out, converged, iters = self._solve(genomes)
+        return out, {"newton_iterations": jnp.sum(iters),
+                     "newton_solves": jnp.full((), genomes.shape[0],
+                                               jnp.int32),
+                     "newton_unconverged": jnp.sum(~converged,
+                                                   dtype=jnp.int32)}
 
     def __call__(self, genomes: jax.Array) -> jax.Array:
         return self.evaluate(genomes)[0]

@@ -12,7 +12,7 @@ Per cell this emits:
   * compiled.memory_analysis()  — proves the cell fits per-device HBM
   * compiled.cost_analysis()    — per-device HLO FLOPs / bytes accessed
   * collective byte counts parsed from the partitioned HLO
-results are appended to a JSON file consumed by benchmarks/roofline.py.
+results are appended to a JSON file (``--out``).
 """
 import argparse
 import json

@@ -167,6 +167,19 @@ Observability (--metrics-dir / --metrics-port / --events-log):
   queue depth and lease counts per run, claim latency, chunk-duration
   histograms, worker busy/idle utilization, per-task cost EMA, and
   autoscaler decisions, from every dispatch backend that emits them.
+  The engine and the compiler add, for every run:
+    chambga_epochs_total, chambga_evaluations_total
+               epochs drained and the evaluations they ran
+    chambga_newton_iterations_total, chambga_newton_solves_total,
+    chambga_newton_unconverged_total
+               (--fitness hvdc) base-case Newton solves, the iterations
+               that updated voltages (each solve runs a fixed schedule;
+               iterations past convergence are masked), and the solves
+               left above tolerance
+    chambga_compile_seconds_total{fun=...,phase=trace|lower|compile}
+               seconds spent compiling, per jitted function
+    chambga_compile_cache_hits_total
+               compiles served by the persistent compilation cache
     --metrics-dir DIR   publish DIR/chambga.prom atomically every ~2s
                (Prometheus textfile exposition — point a node-exporter
                textfile collector, or this repo's terminal dashboard,

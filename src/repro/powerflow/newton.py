@@ -8,6 +8,11 @@ convergence mask freezing finished systems — the SPMD form of "iterate
 until tolerance" (all batch lanes run the same schedule; the broker
 balances predicted iteration counts upstream).
 
+Profiler scopes: ``chambga.newton.mismatch`` (each mismatch, the final
+one included), ``chambga.newton.jacobian`` (``_ds_dv`` and the block
+and mask assembly) and ``chambga.newton.lu`` (the dense solve) name the
+device ops of one iteration.
+
 Hardware adaptation (DESIGN.md §5): pandapower uses sparse LU on CPU; at
 2715 buses a dense factorization is ~2715³*2/3 = 13 GFLOP — 66 µs at v5e
 peak — so dense-on-MXU beats sparse-scalar by orders of magnitude while
@@ -97,15 +102,14 @@ def _newton_powerflow(gridj, p_extra, num_iters, tol, line_mask) -> PFResult:
     q_row = is_pq                                     # Q eqs
 
     def mismatch(vm, va):
-        v = (vm * jnp.exp(1j * va)).astype(cdtype)
-        s = _sbus(ybus, v)
-        dp = jnp.real(s) - p_spec
-        dq = jnp.imag(s) - q_spec
-        return jnp.where(p_row, dp, 0.0), jnp.where(q_row, dq, 0.0), v
+        with jax.named_scope("chambga.newton.mismatch"):
+            v = (vm * jnp.exp(1j * va)).astype(cdtype)
+            s = _sbus(ybus, v)
+            dp = jnp.real(s) - p_spec
+            dq = jnp.imag(s) - q_spec
+            return jnp.where(p_row, dp, 0.0), jnp.where(q_row, dq, 0.0), v
 
-    def body(carry, _):
-        vm, va, done, it = carry
-        dp, dq, v = mismatch(vm, va)
+    def jacobian(v):
         ds_dva, ds_dvm = _ds_dv(ybus, v)
         j11 = jnp.real(ds_dva)                       # dP/dVa
         j12 = jnp.real(ds_dvm)                       # dP/dVm
@@ -121,10 +125,16 @@ def _newton_powerflow(gridj, p_extra, num_iters, tol, line_mask) -> PFResult:
         # identity on masked diagonals keeps the system nonsingular
         j11 = j11 + jnp.diag(1.0 - pr)
         j22 = j22 + jnp.diag(1.0 - qr)
+        return jnp.block([[j11, j12], [j21, j22]])
 
-        jac = jnp.block([[j11, j12], [j21, j22]])
+    def body(carry, _):
+        vm, va, done, it = carry
+        dp, dq, v = mismatch(vm, va)
+        with jax.named_scope("chambga.newton.jacobian"):
+            jac = jacobian(v)
         rhs = -jnp.concatenate([dp, dq])
-        dx = jnp.linalg.solve(jac, rhs)
+        with jax.named_scope("chambga.newton.lu"):
+            dx = jnp.linalg.solve(jac, rhs)
         dva = dx[:n] * p_row
         dvm = dx[n:] * q_row
 
