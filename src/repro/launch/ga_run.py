@@ -173,9 +173,8 @@ Observability (--metrics-dir / --metrics-port / --events-log):
     chambga_newton_iterations_total, chambga_newton_solves_total,
     chambga_newton_unconverged_total
                (--fitness hvdc) base-case Newton solves, the iterations
-               that updated voltages (each solve runs a fixed schedule;
-               iterations past convergence are masked), and the solves
-               left above tolerance
+               they ran (each solve stops at convergence), and the
+               solves left above tolerance
     chambga_compile_seconds_total{fun=...,phase=trace|lower|compile}
                seconds spent compiling, per jitted function
     chambga_compile_cache_hits_total

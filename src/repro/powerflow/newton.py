@@ -3,10 +3,13 @@
 Dense complex linear algebra throughout (MATPOWER's dSbus_dV formulation) —
 the Jacobian assembly is all matmuls/diagonal scalings, ideal for the MXU,
 and the solve is one dense LU per iteration which XLA lowers to the
-platform solver. Iteration count is static (``num_iters``) with a
-convergence mask freezing finished systems — the SPMD form of "iterate
-until tolerance" (all batch lanes run the same schedule; the broker
-balances predicted iteration counts upstream).
+platform solver. The loop (``lax.while_loop``) stops once the solve has
+converged, after at most ``num_iters`` iterations; the step that starts
+from a mismatch under ``tol`` still updates the voltages and counts in
+``iters``. Under ``jax.vmap`` the loop runs until the slowest lane has
+converged and holds the finished lanes fixed; under ``lax.map`` each
+solve stops on its own (the broker balances predicted iteration counts
+upstream).
 
 Profiler scopes: ``chambga.newton.mismatch`` (each mismatch, the final
 one included), ``chambga.newton.jacobian`` (``_ds_dv`` and the block
@@ -127,29 +130,24 @@ def _newton_powerflow(gridj, p_extra, num_iters, tol, line_mask) -> PFResult:
         j22 = j22 + jnp.diag(1.0 - qr)
         return jnp.block([[j11, j12], [j21, j22]])
 
-    def body(carry, _):
-        vm, va, done, it = carry
+    def cond(carry):
+        _, _, done, it = carry
+        return ~done & (it < num_iters)
+
+    def body(carry):
+        vm, va, _, it = carry
         dp, dq, v = mismatch(vm, va)
         with jax.named_scope("chambga.newton.jacobian"):
             jac = jacobian(v)
         rhs = -jnp.concatenate([dp, dq])
         with jax.named_scope("chambga.newton.lu"):
             dx = jnp.linalg.solve(jac, rhs)
-        dva = dx[:n] * p_row
-        dvm = dx[n:] * q_row
-
         err = jnp.maximum(jnp.max(jnp.abs(dp)), jnp.max(jnp.abs(dq)))
-        newly_done = err < tol
-        upd = jnp.where(done, 0.0, 1.0)
-        vm = vm + dvm * upd
-        va = va + dva * upd
-        it = it + jnp.where(done, 0, 1).astype(jnp.int32)
-        done = done | newly_done
-        return (vm, va, done, it), err
+        return vm + dx[n:] * q_row, va + dx[:n] * p_row, err < tol, it + 1
 
-    (vm, va, done, iters), errs = jax.lax.scan(
-        body, (vm0, va0, jnp.zeros((), bool), jnp.zeros((), jnp.int32)),
-        None, length=num_iters)
+    vm, va, _, iters = jax.lax.while_loop(
+        cond, body,
+        (vm0, va0, jnp.zeros((), bool), jnp.zeros((), jnp.int32)))
     dp, dq, _ = mismatch(vm, va)
     final_err = jnp.maximum(jnp.max(jnp.abs(dp)), jnp.max(jnp.abs(dq)))
     return PFResult(vm=vm, va=va, mismatch=final_err,

@@ -8,8 +8,10 @@ from repro.powerflow.contingency import (contingency_loadings,
                                          penalized_objective)
 from repro.powerflow.dc import build_dc_model, dc_flows, screen_contingencies
 from repro.powerflow.grid import make_synthetic_grid
-from repro.powerflow.hvdc import HVDC_LOSS, apply_hvdc
-from repro.powerflow.newton import newton_powerflow, line_flows
+from repro.powerflow.hvdc import (HVDC_LOSS, apply_hvdc,
+                                  scale_genome_to_dispatch)
+from repro.powerflow.newton import (PFResult, _ds_dv, _sbus, line_flows,
+                                    newton_powerflow)
 
 
 @pytest.fixture(scope="module")
@@ -62,6 +64,122 @@ class TestNewton:
         assert not np.allclose(np.asarray(base.va), np.asarray(out.va))
         fl = line_flows(gj, out.vm, out.va, line_mask=mask)
         assert float(fl[3]) == 0.0               # outaged line carries nothing
+
+
+def _masked_scan_powerflow(gridj, p_extra, num_iters, tol=5e-4,
+                           line_mask=None) -> PFResult:
+    """The fixed-schedule form of the solve: all ``num_iters`` iterations
+    run, and a convergence mask freezes the voltages once converged."""
+    bt = gridj["bus_type"]
+    n = bt.shape[0]
+    is_slack, is_pv, is_pq = bt == 2, bt == 1, bt == 0
+    cdtype = gridj["ybus"].dtype
+    if line_mask is None:
+        ybus = gridj["ybus"]
+    else:
+        ys = gridj["y_series"] * line_mask.astype(gridj["y_series"].dtype)
+        bc = (1j * gridj["b_sh"] / 2.0).astype(cdtype) * line_mask
+        f, t = gridj["f_bus"], gridj["t_bus"]
+        ybus = jnp.zeros((n, n), cdtype)
+        ybus = ybus.at[f, f].add(ys + bc)
+        ybus = ybus.at[t, t].add(ys + bc)
+        ybus = ybus.at[f, t].add(-ys)
+        ybus = ybus.at[t, f].add(-ys)
+        ybus = ybus + 1e-6j * jnp.eye(n, dtype=cdtype)
+    p_spec = gridj["p_inj"] + p_extra
+    q_spec = gridj["q_inj"]
+    vm0 = jnp.where(is_slack | is_pv, gridj["v_set"], 1.0)
+    va0 = jnp.zeros((n,), jnp.float32)
+    p_row, q_row = ~is_slack, is_pq
+
+    def mismatch(vm, va):
+        v = (vm * jnp.exp(1j * va)).astype(cdtype)
+        s = _sbus(ybus, v)
+        dp = jnp.real(s) - p_spec
+        dq = jnp.imag(s) - q_spec
+        return jnp.where(p_row, dp, 0.0), jnp.where(q_row, dq, 0.0), v
+
+    def jacobian(v):
+        ds_dva, ds_dvm = _ds_dv(ybus, v)
+        pr = p_row.astype(jnp.float32)
+        qr = q_row.astype(jnp.float32)
+        j11 = jnp.real(ds_dva) * pr[:, None] * pr[None, :]
+        j12 = jnp.real(ds_dvm) * pr[:, None] * qr[None, :]
+        j21 = jnp.imag(ds_dva) * qr[:, None] * pr[None, :]
+        j22 = jnp.imag(ds_dvm) * qr[:, None] * qr[None, :]
+        j11 = j11 + jnp.diag(1.0 - pr)
+        j22 = j22 + jnp.diag(1.0 - qr)
+        return jnp.block([[j11, j12], [j21, j22]])
+
+    def body(carry, _):
+        vm, va, done, it = carry
+        dp, dq, v = mismatch(vm, va)
+        dx = jnp.linalg.solve(jacobian(v), -jnp.concatenate([dp, dq]))
+        err = jnp.maximum(jnp.max(jnp.abs(dp)), jnp.max(jnp.abs(dq)))
+        upd = jnp.where(done, 0.0, 1.0)
+        vm = vm + dx[n:] * q_row * upd
+        va = va + dx[:n] * p_row * upd
+        it = it + jnp.where(done, 0, 1).astype(jnp.int32)
+        return (vm, va, done | (err < tol), it), err
+
+    with jax.default_matmul_precision("highest"):
+        (vm, va, _, iters), _ = jax.lax.scan(
+            body, (vm0, va0, jnp.zeros((), bool), jnp.zeros((), jnp.int32)),
+            None, length=num_iters)
+        dp, dq, _ = mismatch(vm, va)
+    final_err = jnp.maximum(jnp.max(jnp.abs(dp)), jnp.max(jnp.abs(dq)))
+    return PFResult(vm=vm, va=va, mismatch=final_err,
+                    converged=final_err < tol, iters=iters)
+
+
+class TestNewtonStopsAtConvergence:
+    """The solve stops once converged and returns, bit for bit, what the
+    fixed masked schedule returns, one genome at a time or batched."""
+
+    @pytest.mark.parametrize("batching,num_iters,outage", [
+        ("map", 10, False), ("vmap", 10, False), ("map", 2, False),
+        ("vmap", 2, False), ("map", 10, True)])
+    def test_bit_identical_to_masked_schedule(self, gj, batching, num_iters,
+                                              outage):
+        genomes = jax.random.uniform(jax.random.PRNGKey(11), (6, 4),
+                                     minval=-1.0, maxval=1.0)
+        mask = (jnp.ones(gj["rate"].shape[0]).at[3].set(0.0) if outage
+                else None)
+
+        def solves(solve):
+            def one(g):
+                p_extra = apply_hvdc(gj, scale_genome_to_dispatch(gj, g))
+                return solve(p_extra)
+            if batching == "map":
+                return jax.jit(lambda gs: jax.lax.map(one, gs))(genomes)
+            return jax.jit(jax.vmap(one))(genomes)
+
+        got = solves(lambda p: newton_powerflow(
+            gj, p_extra=p, num_iters=num_iters, line_mask=mask))
+        want = solves(lambda p: _masked_scan_powerflow(
+            gj, p, num_iters, line_mask=mask))
+        for field in PFResult._fields:
+            np.testing.assert_array_equal(np.asarray(getattr(got, field)),
+                                          np.asarray(getattr(want, field)),
+                                          err_msg=field)
+        iters = np.asarray(got.iters)
+        if num_iters == 2:
+            assert not np.any(got.converged) and np.all(iters == 2)
+        else:
+            assert np.all(got.converged) and iters.max() < num_iters
+
+    def test_loop_is_a_while_not_a_scan(self, gj):
+        def primitives(jaxpr):
+            for eqn in jaxpr.eqns:
+                yield eqn.primitive.name
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from primitives(sub)
+
+        closed = jax.make_jaxpr(
+            lambda p: newton_powerflow(gj, p_extra=p, num_iters=10))(
+                jnp.zeros_like(gj["p_inj"]))
+        assert "while" in [e.primitive.name for e in closed.jaxpr.eqns]
+        assert "scan" not in set(primitives(closed.jaxpr))
 
 
 class TestHVDC:
