@@ -7,11 +7,12 @@
 
 In one process, for each of ``--seeds``: the cell's set-up and a window of
 ``--seconds`` through the harness's own ``drive``, then the numbers of
-``compare.numbers`` (the lower readings). For each of ``--control-seeds``
-the same with the control in the program's place, the program in the
-precision below the configuration's (the upper readings). One JSON line
-per seed. ``--control-precision`` hands the control another precision;
-``--save-trace`` also records a traced window of the first seed.
+the cell's comparison, ``run.numbers`` (the lower readings). For each of
+``--control-seeds`` the same with the control in the program's place,
+the program in the precision below the configuration's (the upper
+readings). One JSON line per seed. ``--control-precision`` hands the
+control another precision; ``--save-trace`` also records a traced window
+of the first seed.
 Needs the cell's chips, like ``run.py``.
 """
 import argparse
@@ -26,18 +27,15 @@ ROOT = os.path.dirname(HERE)
 sys.path[0] = ROOT
 sys.path.insert(1, os.path.join(ROOT, "src"))
 
-from chipbench import compare, run  # noqa: E402
+from chipbench import run  # noqa: E402
 
 
 def readings(jax, dep, seconds: float) -> dict:
     t = time.monotonic()
     out = run.drive(jax, dep, seconds, False)
     drive_s = time.monotonic() - t
-    gap = dep.limits[f"{dep.fitness_name}_gap"]
     t = time.monotonic()
-    nums, wrong = compare.numbers(
-        dep.fitness_name, dep.reference, dep.ga, out["start_pop"],
-        out["window_pop"], out["replay_pop"], dep.match_tol, gap)
+    nums, wrong = run.numbers(dep, out)
     return {"epochs": out["epochs"], "window_s": out["window_s"],
             "setup_s": out["setup_s"], "drive_s": drive_s,
             "reference_s": time.monotonic() - t,

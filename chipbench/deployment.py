@@ -14,7 +14,8 @@ class Deployment:
     ctx: Any                        # the program's ShardingCtx, or None
     ga_seed: int                    # 32-bit GA seed drawn from --seed
     fitness_name: str               # names the fitness gap: "<name>_gap"
-    reference: Callable             # (N, G) genomes -> (N,) reference fitness
+    reference: Callable             # (N, G) genomes -> (N, M) reference
+                                    # fitness, or (N,) for one objective
     control: Callable[..., ContextManager]  # the program in the precision
                                             # below, while the context holds
     ga: dict                        # GA settings for the reference
@@ -53,7 +54,8 @@ def program_config(conf: dict, chips: int, seed: int):
                          "migration of one migrant")
     return GAConfig(
         num_genes=conf["num_genes"], pop_per_island=conf["pop_per_island"],
-        num_islands=conf["islands_per_chip"] * chips, num_objectives=1,
+        num_islands=conf["islands_per_chip"] * chips,
+        num_objectives=conf.get("num_objectives", 1),
         generations_per_epoch=conf["generations_per_epoch"],
         mutation_prob=conf["mutation_prob"],
         mutation_eta=conf["mutation_eta"],

@@ -19,7 +19,9 @@ and the builder ``configs/<name>.py``) and a traffic mix
    is the evaluations of the epochs completed over the window's wall time.
    With ``--trace 1`` the same window is followed by
    ``trace_epochs`` epochs under the profiler, and the run reports the
-   cell's per-layer metrics, each read by ``metrics/<name>.py``;
+   cell's per-layer metrics, each read by ``metrics/<name>.py``; the
+   ops of the trace are named by the program scopes of the compiled
+   epoch step, read from its HLO text after the traced epochs;
 4. runs the window's compiled epoch step once more, reads the device's
    peak memory, frees the program, and compares both populations with the
    plain reference (``compare.py``), which decides ``correct``.
@@ -95,7 +97,7 @@ def chips_or_fail(jax, chips: int) -> dict:
 
 def host_pop(jax, pop) -> dict:
     g, f, r = jax.device_get((pop.genomes, pop.fitness, pop.rng))
-    return {"genomes": g, "fitness": f[..., 0], "rng": r}
+    return {"genomes": g, "fitness": f, "rng": r}
 
 
 def drive(jax, dep, seconds: float, traced: bool) -> dict:
@@ -124,7 +126,12 @@ def drive(jax, dep, seconds: float, traced: bool) -> dict:
                 pop, traced_hist = eng.run(pop, epochs=dep.trace_epochs)
                 pop = jax.block_until_ready(pop)
             jax.profiler.stop_trace()
-            out["trace"] = tracing.from_profile_dir(trace_dir)
+            t_scopes = time.monotonic()
+            out["traced_s"] = t_scopes - t0 - out["window_s"]
+            scopes = tracing.scope_map(
+                eng._epoch_step.lower(pop).compile().as_text())
+            out["scopes_s"] = time.monotonic() - t_scopes
+            out["trace"] = tracing.from_profile_dir(trace_dir, scopes)
             out["traced_epochs"] = len(traced_hist)
         finally:
             shutil.rmtree(trace_dir, ignore_errors=True)
@@ -134,7 +141,6 @@ def drive(jax, dep, seconds: float, traced: bool) -> dict:
     # compiled step on the same state gives what the window's first epoch
     # gave, which the reference follows epoch for epoch
     t1 = time.monotonic()
-    out["traced_s"] = t1 - t0 - out["window_s"]
     replay, _ = eng.run(start, epochs=1)
     out["start_pop"] = host_pop(jax, start)
     out["window_pop"] = host_pop(jax, pop)
@@ -175,15 +181,20 @@ def per_layer(bench: dict, cell: str, dep, run: dict, device: dict) -> dict:
     return out
 
 
+def numbers(dep, run: dict) -> tuple:
+    """``compare.numbers`` of the run's populations: (numbers, wrong
+    evaluations)."""
+    return compare.numbers(
+        dep.fitness_name, dep.reference, dep.ga, run["start_pop"],
+        run["window_pop"], run["replay_pop"], dep.match_tol,
+        dep.limits[f"{dep.fitness_name}_gap"])
+
+
 def check(dep, run: dict) -> tuple:
     """(correct, failed, numbers with limits) against the reference.
     ``failed`` counts the checked evaluations whose fitness is off by more
     than the limit, and reference solves that did not converge."""
-    gap = f"{dep.fitness_name}_gap"
-    nums, wrong = compare.numbers(dep.fitness_name, dep.reference, dep.ga,
-                                  run["start_pop"], run["window_pop"],
-                                  run["replay_pop"], dep.match_tol,
-                                  dep.limits[gap])
+    nums, wrong = numbers(dep, run)
     checks = {k: {"value": v, "limit": dep.limits[k]} for k, v in nums.items()}
     correct = all(v["value"] <= v["limit"] for v in checks.values())
     return correct, wrong + dep.unconverged(), checks
@@ -216,7 +227,8 @@ def main(argv=None) -> int:
     correct, failed, checks = check(dep, run)
     print("phases (s): " + ", ".join(
         f"{k} {run[k + '_s']:.1f}" for k in ("setup", "window", "traced",
-                                             "replay"))
+                                             "scopes", "replay")
+        if k + "_s" in run)
           + f", check {time.monotonic() - t_check:.1f}", file=sys.stderr)
 
     result = {"correct": correct,

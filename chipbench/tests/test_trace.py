@@ -2,6 +2,7 @@
 known answers and on a trace recorded on a TPU v5 lite."""
 import importlib
 import os
+from types import SimpleNamespace as NS
 
 import pytest
 
@@ -11,8 +12,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 RECORDED = os.path.join(HERE, "data", "hvdc_horizontal_trace.json.gz")
 
 
-def _op(name, s, e):
-    return T.Op(name, float(s), float(e))
+def _op(name, s, e, scope=""):
+    return T.Op(name, float(s), float(e), scope)
 
 
 def _hand_trace():
@@ -45,13 +46,6 @@ def test_breakdown_names_gaps_by_innermost_span():
     # self time, averaged over the 2 chips: while.1 holds fusion.3 for
     # 500 ns of its 3000 on chip 0
     assert b["device_ops"][0] == ["%while.1", pytest.approx(2500e-9 / 2)]
-
-
-def test_save_load_round_trip(tmp_path):
-    tr = _hand_trace()
-    tr.save(str(tmp_path / "t.json.gz"))
-    back = T.Trace.load(str(tmp_path / "t.json.gz"))
-    assert back.devices == tr.devices and back.window == tr.window
 
 
 def _read(name, trace, **info):
@@ -88,10 +82,14 @@ def test_lu_reader_takes_the_calls_not_their_readers():
                  islands_per_chip=1, pop_per_island=2) == pytest.approx(2e-3)
 
 
+SCOPE_READERS = ("selection_ms", "variation_ms", "survivor_ms",
+                 "migration_ms", "framework_overhead_share")
+
+
 def test_readers_find_nothing_on_an_empty_trace():
     tr = T.Trace(devices=[], spans=[], window=(0.0, 1.0))
     for name in ("device_idle_share", "epoch_device_ms", "newton_solve_ms",
-                 "newton_lu_ms"):
+                 "newton_lu_ms") + SCOPE_READERS:
         assert _read(name, tr) is None, name
 
 
@@ -112,3 +110,132 @@ def test_readers_on_recorded_trace():
     ops = dict(T.breakdown(tr)["device_ops"])
     assert max(ops, key=ops.get) == "%fusion.2305"
     assert ops["%custom-call.1785"] == pytest.approx(485666e-9)
+    # recorded before ops carried scopes: the scope readers find nothing
+    for name in SCOPE_READERS:
+        assert _read(name, tr, **one) is None, name
+
+
+def _scoped_trace():
+    """One chip, window [0, 10000] ns, every op under a scope or not:
+    selection 1000 ns, variation 500, the fitness's while loop 4000 of
+    which its LU body op 2500, survivor 1000, migration 300, an unscoped
+    copy 200, then 3000 idle."""
+    ops = [_op("%a = sel", 0, 1000, "chambga.selection"),
+           _op("%b = var", 1000, 1500, "chambga.variation"),
+           _op("%w = while", 1500, 5500, "chambga.fitness"),
+           _op("%lu = call", 2000, 4500,
+               "chambga.fitness/chambga.newton.lu"),
+           _op("%c = surv", 5500, 6500, "chambga.survivor"),
+           _op("%d = mig", 6500, 6800, "chambga.migration"),
+           _op("%copy = copy", 6800, 7000, "")]
+    return T.Trace(devices=[ops], spans=[], window=(0.0, 10000.0))
+
+
+def test_scope_readers_on_hand_trace():
+    tr = _scoped_trace()
+    # every op's self time lands in one scope: together, the busy time
+    assert sum(tr.scope_s()[0].values()) == pytest.approx(tr.busy_s()[0])
+    assert tr.scope_s()[0]["chambga.fitness"] == pytest.approx(1500e-9)
+    one = dict(epochs=1, generations_per_epoch=2)
+    assert _read("selection_ms", tr, **one) == pytest.approx(1000e-6 / 2)
+    assert _read("variation_ms", tr, **one) == pytest.approx(500e-6 / 2)
+    assert _read("survivor_ms", tr, **one) == pytest.approx(1000e-6 / 2)
+    assert _read("migration_ms", tr, **one) == pytest.approx(300e-6)
+    # outside the fitness: 1000 + 500 + 1000 + 300 + 200 of 7000 busy
+    assert _read("framework_overhead_share", tr, **one) == pytest.approx(
+        100 * 3000 / 7000)
+
+
+def test_scope_readers_skip_a_scope_with_no_ops():
+    tr = _scoped_trace()
+    tr.devices[0] = [o for o in tr.devices[0]
+                     if o.scope != "chambga.migration"]
+    assert _read("migration_ms", tr) is None
+    assert _read("selection_ms", tr) is not None
+
+
+def _profile(ops, modules, window=(0, 10000)):
+    """A profile as ``jax.profiler.ProfileData`` gives it: one TPU plane
+    with its ops and modules lines, one host plane with the window."""
+    def events(spec):
+        return [NS(name=n, start_ns=float(s), end_ns=float(e))
+                for n, s, e in spec]
+    device = NS(name="/device:TPU:0", lines=[
+        NS(name="XLA Modules", events=events(modules)),
+        NS(name="XLA Ops", events=events(ops))])
+    host = NS(name="/host:CPU", lines=[
+        NS(name="python3", events=events([("chipbench.window", *window)]))])
+    return NS(planes=[device, host])
+
+
+def test_scopes_go_to_the_programs_ops_alone():
+    scopes = ("jit_epoch_step", {"%fusion.1": "chambga.selection",
+                                 "%while.2": "chambga.fitness"})
+    pd = _profile(
+        ops=[("%fusion.1 = f32[8] fusion(%p)", 100, 200),
+             ("%while.2 = (f32[8]) while(%t)", 200, 900),
+             ("%fusion.3 = f32[8] fusion(%q)", 900, 1000),
+             ("%fusion.1 = f32[2] fusion(%x)", 2100, 2200),   # jit_copy's
+             ("%fusion.1 = f32[8] fusion(%p)", 3000, 3100),   # outside
+             ("%fusion.1 = f32[8] fusion(%p)", 4100, 4200)],
+        modules=[("jit_epoch_step(123)", 100, 1000),
+                 ("jit_copy(9)", 2000, 2500),
+                 ("jit_epoch_step(123)", 4000, 5000)])
+    tr = T.from_profile(pd, scopes)
+    assert [o.scope for o in tr.devices[0]] == [
+        "chambga.selection", "chambga.fitness", "", "", "",
+        "chambga.selection"]
+    # without a map no op has a scope
+    assert {o.scope for o in T.from_profile(pd).devices[0]} == {""}
+
+
+def test_scope_map_reads_innermost_last():
+    text = """HloModule jit_epoch_step, is_scheduled=true
+
+%body (p: f32[8]) -> f32[8] {
+  %lu.1 = f32[8] custom-call(f32[8] %p), metadata={op_name="jit(epoch_step)/while/body/chambga.fitness/jit(_solve)/chambga.newton.lu/lu" source_file="x.py"}
+  ROOT %fusion.4 = f32[8] fusion(f32[8] %lu.1), kind=kLoop, metadata={op_name="jit(epoch_step)/while/body/vmap(chambga.variation)/mul"}
+}
+
+ENTRY %main (a: f32[8]) -> f32[8] {
+  %copy.2 = f32[8] copy(f32[8] %a)
+  %sort.3 = f32[8] sort(f32[8] %copy.2), metadata={op_name="jit(epoch_step)/while/sort"}
+  ROOT %while.5 = f32[8] while(f32[8] %sort.3), body=%body
+}
+"""
+    module, scopes = T.scope_map(text)
+    assert module == "jit_epoch_step"
+    assert scopes == {"%lu.1": "chambga.fitness/chambga.newton.lu",
+                      "%fusion.4": "chambga.variation"}
+
+
+@pytest.mark.parametrize("make", [_hand_trace, _scoped_trace])
+def test_save_load_round_trip(tmp_path, make):
+    tr = make()
+    tr.save(str(tmp_path / "t.json.gz"))
+    back = T.Trace.load(str(tmp_path / "t.json.gz"))
+    assert back.devices == tr.devices and back.window == tr.window
+
+
+def test_every_scope_of_the_epoch_step_names_an_op():
+    """The 60-bus HVDC epoch step compiled on the CPU: each ``chambga.*``
+    name in its HLO is the innermost scope of some op of the map, and the
+    map holds the program's GA and Newton scopes."""
+    import jax
+    from chipbench.configs import hvdc_german
+    from chipbench.tests.test_harness_cpu import MIX, tiny_hvdc
+    from repro.core.engine import GAEngine
+
+    dep = hvdc_german.build(tiny_hvdc(), MIX, seed=1, chips=1)
+    eng = GAEngine(dep.cfg, dep.fitness, cost_fn=dep.cost_fn, ctx=dep.ctx)
+    text = eng._epoch_step.lower(eng.init(dep.ga_seed)).compile().as_text()
+    module, scopes = T.scope_map(text)
+    assert module == "jit_epoch_step"
+    innermost = {v.rsplit("/", 1)[-1] for v in scopes.values()}
+    assert set(T.SCOPE.findall(text)) <= innermost
+    assert innermost >= {"chambga.selection", "chambga.variation",
+                         "chambga.fitness", "chambga.survivor",
+                         "chambga.migration", "chambga.newton.mismatch",
+                         "chambga.newton.jacobian", "chambga.newton.lu"}
+    assert all(v.startswith("chambga.fitness/") for v in scopes.values()
+               if "newton" in v)

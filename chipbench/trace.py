@@ -6,6 +6,14 @@ xplane), the harness's own host spans (``chipbench.*`` annotations), and
 the traced window: the ``chipbench.window`` span. Times are nanoseconds on
 the profiler's common clock.
 
+An op's ``scope`` is the program's ``chambga.*`` scope names in its
+``op_name``, outermost first and joined by ``/``: the last is the
+innermost. Device events carry no ``op_name``, so ``scope_map`` reads the
+scopes from the compiled program's HLO text, by op name (a fusion carries
+its root's), and ``from_profile`` gives them to the ops that ran inside
+that program's module (the ``XLA Modules`` line): op names repeat across
+programs. An op of another program, or with no such name, has scope ``""``.
+
 ``Trace.save``/``Trace.load`` keep the same content as JSON, which is how
 the recorded trace the tests read is kept.
 """
@@ -17,12 +25,16 @@ import gzip
 import json
 import os
 import re
-from typing import Callable, Iterable, List, Tuple
+from typing import Callable, Iterable, List, Optional, Tuple
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
 SPAN_PREFIX = "chipbench."
 WINDOW = "chipbench.window"
+SCOPE = re.compile(r"chambga\.[a-z_.]*[a-z]")
+HLO_OP = re.compile(r'^\s*(?:ROOT )?(%[^\s=]+) = .*?op_name="([^"]*)"',
+                    re.M)
 
 
 @dataclasses.dataclass
@@ -30,6 +42,7 @@ class Op:
     name: str
     start: float
     end: float
+    scope: str = ""
 
     @property
     def dur(self) -> float:
@@ -55,6 +68,11 @@ class Trace:
     def busy_s(self) -> List[float]:
         """Per chip, seconds in the window during which any op ran."""
         return [union_ns(dev, self.window) * 1e-9 for dev in self.devices]
+
+    def scope_s(self) -> List[dict]:
+        """Per chip, self time (s) in the window per op scope."""
+        return [self_times(dev, self.window, key=lambda o: o.scope)
+                for dev in self.devices]
 
     def save(self, path: str) -> None:
         data = {"window": list(self.window),
@@ -106,28 +124,60 @@ def idle_gaps(ops: List[Op], window: Tuple[float, float]) -> List[Tuple[float, f
     return [(s, e) for s, e in gaps if e > s]
 
 
-def from_profile_dir(path: str) -> Trace:
-    """Read the one ``*.xplane.pb`` the profiler wrote under ``path``."""
+def scope_map(hlo_text: str) -> Tuple[str, dict]:
+    """(module name, {op name: scope}) from a compiled program's HLO text."""
+    module = re.match(r"HloModule ([^\s,]+)", hlo_text).group(1)
+    scopes = {}
+    for name, op_name in HLO_OP.findall(hlo_text):
+        names = SCOPE.findall(op_name)
+        if names:
+            scopes[name] = "/".join(names)
+    return module, scopes
+
+
+def from_profile_dir(path: str,
+                     scopes: Optional[Tuple[str, dict]] = None) -> Trace:
+    """Read the one ``*.xplane.pb`` the profiler wrote under ``path``;
+    ``scopes``, from ``scope_map``, names the ops of that program."""
     from jax.profiler import ProfileData
 
     files = glob.glob(os.path.join(path, "**", "*.xplane.pb"), recursive=True)
     if len(files) != 1:
         raise RuntimeError(f"expected one xplane file under {path}: {files}")
-    return from_profile(ProfileData.from_file(files[0]))
+    return from_profile(ProfileData.from_file(files[0]), scopes)
 
 
-def from_profile(pd) -> Trace:
+def _scoped(ops: List[Op], modules: List[Op], scopes) -> None:
+    """Give each op that starts inside a run of the ``scopes`` program its
+    scope. ``ops`` and ``modules`` are ordered by start."""
+    if scopes is None:
+        return
+    module, by_name = scopes
+    runs = [m for m in modules if m.name.split("(", 1)[0] == module]
+    k = 0
+    for o in ops:
+        while k < len(runs) and runs[k].end <= o.start:
+            k += 1
+        if k < len(runs) and runs[k].start <= o.start:
+            o.scope = by_name.get(short_name(o.name), "")
+
+
+def from_profile(pd, scopes: Optional[Tuple[str, dict]] = None) -> Trace:
     devices, spans = {}, []
     for plane in pd.planes:
         m = DEVICE_PLANE.match(plane.name)
         if m:
-            ops = []
+            ops, modules = [], []
             for line in plane.lines:
-                if line.name != OPS_LINE:
-                    continue
-                ops.extend(Op(ev.name, ev.start_ns, ev.end_ns)
-                           for ev in line.events)
-            devices[int(m.group(1))] = sorted(ops, key=lambda o: o.start)
+                if line.name == OPS_LINE:
+                    ops.extend(Op(ev.name, ev.start_ns, ev.end_ns)
+                               for ev in line.events)
+                elif line.name == MODULES_LINE:
+                    modules.extend(Op(ev.name, ev.start_ns, ev.end_ns)
+                                   for ev in line.events)
+            ops.sort(key=lambda o: o.start)
+            _scoped(ops, sorted(modules, key=lambda o: o.start), scopes)
+            devices[int(m.group(1))] = ops
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 spans.extend(Op(ev.name, ev.start_ns, ev.end_ns)
@@ -146,19 +196,22 @@ def short_name(name: str) -> str:
     return name.split(" = ", 1)[0]
 
 
-def self_times(ops: List[Op], window: Tuple[float, float]) -> dict:
-    """Seconds per op name inside the window, less the time of the ops
-    nested inside it on the same line (a while loop's body ops)."""
+def self_times(ops: List[Op], window: Tuple[float, float],
+               key: Callable[[Op], str] = lambda o: short_name(o.name)
+               ) -> dict:
+    """Seconds per ``key`` (by default the op's name) inside the window,
+    less the time of the ops nested inside each op on the same line (a
+    while loop's body ops)."""
     lo, hi = window
     out: dict = {}
-    stack: list = []                      # [end, name] of enclosing ops
+    stack: list = []                      # [end, key] of enclosing ops
     for o in sorted(ops, key=lambda o: (o.start, -o.end)):
         s, e = max(o.start, lo), min(o.end, hi)
         if e <= s:
             continue
         while stack and stack[-1][0] <= s:
             stack.pop()
-        name = short_name(o.name)
+        name = key(o)
         if stack:
             out[stack[-1][1]] -= (e - s) * 1e-9
         out[name] = out.get(name, 0.0) + (e - s) * 1e-9
